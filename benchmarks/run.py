@@ -22,6 +22,8 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import importlib
     print("name,us_per_call,derived")
     failures = []

@@ -1492,10 +1492,11 @@ def run():
 if __name__ == "__main__":
     import sys
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "--sharded":
-        # must land in XLA_FLAGS before anything imports jax (the
-        # module itself only imports numpy at top level, so this is
-        # still early enough here)
+        # must land in XLA_FLAGS before JAX starts a backend (importing
+        # jax does not start one, so this is still early enough here)
         flags = os.environ.get("XLA_FLAGS", "")
         if "--xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (

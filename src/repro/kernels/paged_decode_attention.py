@@ -7,22 +7,26 @@ in the pages named by its block table, page ``j`` holding absolute
 positions ``[j*page_size, (j+1)*page_size)``.  The block tables and
 per-sequence lengths are **scalar-prefetched**
 (``pltpu.PrefetchScalarGridSpec``) so the kv BlockSpec ``index_map`` can
-dereference the table: grid step ``(b, h, j)`` DMAs page
-``block_tables[b, j]`` straight from the pool — the gather happens in
-the DMA engine, never materializing a contiguous copy of the sequence.
+dereference the table: grid step ``(b, j)`` DMAs page
+``block_tables[b, j]`` — all of its KV heads — straight from the pool;
+the gather happens in the DMA engine, never materializing a contiguous
+copy of the sequence.
 
-Grid: (batch, kv_heads, max_pages) — the page axis is minor-most, so the
-online-softmax scratch (running max / denominator / accumulator)
-persists across it, exactly like the contiguous ``decode_attention``
-kernel.  Table entries past ``ceil(kv_len/page_size)`` point at the
-scratch page 0; their positions fail the ``kpos < kv_len`` mask, so
-stale data there (or in a freshly allocated page's tail) is never read —
-the paged layout's overwrite-before-read guarantee.
+Grid: (batch, max_pages) — the page axis is minor-most, so the
+online-softmax scratch (running max / denominator / accumulator, one
+row per query head) persists across it.  A static loop over the KV
+heads runs inside each step.  Table entries past
+``ceil(kv_len/page_size)`` point at the scratch page 0; their positions
+fail the ``kpos < kv_len`` mask, so stale data there (or in a freshly
+allocated page's tail) is never read — the paged layout's
+overwrite-before-read guarantee.
 
-BlockSpec tiling (VMEM):
-    q:     (1, 1, g*D)            — the g = H/Hkv query heads per kv head
-    k, v:  (1, page_size, 1, D)   — one streamed KV page
-    out:   (1, 1, g*D)            — written on the last page
+BlockSpec tiling (VMEM).  The TPU lowering needs each block's two
+minor dimensions to be multiples of (8, 128) or the array's own, so
+every block keeps the array's trailing dimensions whole:
+    q:     (1, Hkv, g, D)         — the g = H/Hkv query heads per kv head
+    k, v:  (1, page_size, Hkv, D) — one streamed KV page, every kv head
+    out:   (1, Hkv, g, D)         — written on the last page
 """
 from __future__ import annotations
 
@@ -38,9 +42,10 @@ NEG_INF = -1e30
 
 
 def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
-            page_size: int, n_pages_grid: int, g: int, scale: float):
+            page_size: int, n_pages_grid: int, n_kv_heads: int, g: int,
+            scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -48,31 +53,30 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    D = k_ref.shape[-1]
-    q = q_ref[0, 0, :].reshape(g, D).astype(F32) * scale   # (g, D)
-    k = k_ref[0, :, 0, :].astype(F32)                      # (ps, D)
-    v = v_ref[0, :, 0, :].astype(F32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, ps)
-
     kv_len = len_ref[b]
     kpos = (j * page_size
             + jax.lax.broadcasted_iota(jnp.int32, (g, page_size), 1))
-    s = jnp.where(kpos < kv_len, s, NEG_INF)
+    valid = kpos < kv_len
+    for h in range(n_kv_heads):
+        q = q_ref[0, h].astype(F32) * scale                  # (g, D)
+        k = k_ref[0, :, h, :].astype(F32)                    # (ps, D)
+        v = v_ref[0, :, h, :].astype(F32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, ps)
+        s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1)
-    acc_s[...] = (acc_s[...] * corr[:, None]
-                  + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
-    m_s[...] = m_new
+        m_prev = m_s[h]                                      # (g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[h] = (acc_s[h] * corr
+                    + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
+        m_s[h] = m_new
 
     @pl.when(j == n_pages_grid - 1)
     def _finish():
         l_safe = jnp.maximum(l_s[...], 1e-30)
-        o_ref[0, 0, :] = (acc_s[...] / l_safe[:, None]).reshape(-1).astype(
-            o_ref.dtype)
+        o_ref[0] = (acc_s[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -86,39 +90,37 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, kv_len,
     page_size, Hkv = k_pages.shape[1], k_pages.shape[2]
     g = H // Hkv
     max_pages = block_tables.shape[1]
-    grid = (B, Hkv, max_pages)
 
     kernel = functools.partial(_kernel, page_size=page_size,
-                               n_pages_grid=max_pages, g=g,
+                               n_pages_grid=max_pages, n_kv_heads=Hkv, g=g,
                                scale=D ** -0.5)
-    qg = q.reshape(B, Hkv, g * D)
+    qg = q.reshape(B, Hkv, g, D)
     bt = jnp.asarray(block_tables, jnp.int32)
     kv_len_arr = jnp.broadcast_to(
         jnp.asarray(kv_len, jnp.int32).reshape(-1), (B,))
 
+    kv_spec = pl.BlockSpec((1, page_size, Hkv, D),     # page j of sequence
+                           lambda b, j, bt, kl: (bt[b, j], 0, 0, 0))  # b
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # block tables + kv lengths
-        grid=grid,
+        grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, g * D), lambda b, h, j, bt, kl: (b, h, 0)),
-            # the paged gather: page j of sequence b via its block table
-            pl.BlockSpec((1, page_size, 1, D),
-                         lambda b, h, j, bt, kl: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D),
-                         lambda b, h, j, bt, kl: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, Hkv, g, D), lambda b, j, bt, kl: (b, 0, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, g * D),
-                               lambda b, h, j, bt, kl: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, g, D),
+                               lambda b, j, bt, kl: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g,), F32),                 # running max
-            pltpu.VMEM((g,), F32),                 # denominator
-            pltpu.VMEM((g, D), F32),               # output accumulator
+            pltpu.VMEM((Hkv, g, 1), F32),          # running max
+            pltpu.VMEM((Hkv, g, 1), F32),          # denominator
+            pltpu.VMEM((Hkv, g, D), F32),          # output accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g * D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret,
     )(bt, kv_len_arr, qg, k_pages, v_pages)
     return out.reshape(B, H, D)

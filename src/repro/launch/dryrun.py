@@ -1,9 +1,11 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 on the production meshes with 512 placeholder host devices, and extract
 memory / cost / collective statistics for EXPERIMENTS.md.
+
+The dry-run is written for the CPU host platform: ``dryrun_one`` pins
+JAX to it (``pin_host_platform``) before the first backend starts, so
+on a machine with an accelerator the placeholder mesh is still built
+from host devices.  Importing this module touches no device state.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-360m \
@@ -13,6 +15,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -31,6 +34,20 @@ from repro.launch.mesh import make_production_mesh
 from repro.models.pspec import set_mesh_rules
 from repro.training import optim
 
+
+HOST_DEVICES = 512
+_DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
+
+
+def pin_host_platform() -> None:
+    """Select the CPU platform with ``HOST_DEVICES`` placeholder devices.
+    Appends to ``XLA_FLAGS`` (a count already there wins) and only takes
+    effect if no JAX backend has started in this process yet."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if _DEVICE_COUNT_FLAG not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} {_DEVICE_COUNT_FLAG}={HOST_DEVICES}".strip())
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _moment_dtype(cfg) -> str:
@@ -53,6 +70,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         return {"arch": arch, "shape": shape_name, "skipped": True,
                 "reason": "unsupported pair (DESIGN.md §6)"}
 
+    pin_host_platform()
     lmap = SH.SHARDING_PRESETS[sharding]
     mesh = make_production_mesh(multi_pod=multi_pod)
     set_mesh_rules(mesh, lmap)
@@ -141,6 +159,8 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS))
     ap.add_argument("--shape", choices=list(INPUT_SHAPES))

@@ -7,17 +7,27 @@ benches must keep seeing 1).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes the compiler partitions (GSPMD ``Auto``): the
+    models place arrays with ``with_sharding_constraint`` and leave the
+    rest to propagation.  ``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which sharded gathers (an embedding lookup into a
+    vocab-sharded table) must name their output sharding and fail."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh for tests/examples on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_serving_mesh(n_devices=None):
@@ -30,7 +40,7 @@ def make_serving_mesh(n_devices=None):
     bench lane run without accelerators."""
     if n_devices is None:
         n_devices = len(jax.devices())
-    return jax.make_mesh((1, n_devices), ("data", "model"))
+    return _auto_mesh((1, n_devices), ("data", "model"))
 
 
 # TPU v5e hardware constants for the roofline analysis (per chip)

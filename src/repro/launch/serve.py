@@ -28,6 +28,8 @@ def main():
                          "(batch = number of requests, slots = --batch)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.dry_run:
         from repro.launch.dryrun import dryrun_one
         dryrun_one(args.arch, args.shape)

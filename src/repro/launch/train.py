@@ -30,6 +30,8 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.dry_run:
         from repro.launch.dryrun import dryrun_one
         dryrun_one(args.arch, args.shape)

@@ -3,10 +3,10 @@
 Three execution paths:
   * ``chunked_attention`` — pure-jnp flash-style attention: a
     ``lax.scan`` over query blocks with fp32 softmax, bounding peak
-    activation memory to (block_q x seq) instead of (seq x seq).  This is
-    the path the multi-pod dry-run lowers (TPU kernels cannot compile on
-    the CPU host platform); on real TPU ``repro.kernels.ops`` swaps in
-    the Pallas flash kernel.
+    activation memory to (block_q x seq) instead of (seq x seq).  It
+    serves every platform: prefill chunks, speculative verify, and the
+    decode paths the Pallas paged kernel does not take (sliding window,
+    sharded pools).  No model path calls a Pallas flash kernel.
   * ``triangular`` — causal block-skipping variant (perf pass): query
     blocks are unrolled and each attends only keys ``<= block_end``,
     halving attention FLOPs vs the chunked path.

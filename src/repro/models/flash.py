@@ -15,9 +15,10 @@ FlashAttention-2 scheme in plain jnp:
     blocks, recomputing probabilities from the stored LSE.  dQ
     accumulates via dynamic-update-slice-add into the outer carry.
 
-``repro.kernels.flash_attention`` is the Pallas/TPU twin of the forward
-pass; this is the lowering used by the dry-run (Mosaic cannot compile on
-the CPU host platform) and the oracle the kernel is tested against.
+This is the lowering on every platform, the TPU included.
+``repro.kernels.flash_attention`` is a Pallas twin of the forward pass
+that only the kernel tests call; the v5e compiler refuses its block
+shapes as written.
 
 Layout: grouped GQA — q: (B, Sq, Hkv, g, D); k/v: (B, Skv, Hkv, D).
 """

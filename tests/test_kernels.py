@@ -69,6 +69,8 @@ def test_decode_attention_kernel_unaligned_cache(B, S, H, Hkv, D, S_odd):
     (2, 4, 2, 64, 16, 4),
     (3, 8, 1, 32, 8, 6),
     (1, 2, 2, 128, 16, 2),
+    (3, 15, 5, 64, 16, 4),                        # smollm-360m heads (g=3)
+    (2, 8, 4, 48, 16, 3),                         # tiansuan-ground heads
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_kernel(B, H, Hkv, D, ps, max_bt, dtype):
