@@ -122,5 +122,6 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, kv_len,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention_kernel",   # the op's name in a trace
     )(bt, kv_len_arr, qg, k_pages, v_pages)
     return out.reshape(B, H, D)

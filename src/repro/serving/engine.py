@@ -237,6 +237,17 @@ PREFILLING = "prefill"
 DECODING = "decode"
 
 
+def chunk_bucket(n_tokens: int, max_seq: int) -> int:
+    """Jit bucket for a prompt chunk of ``n_tokens`` real tokens: next
+    power of two (floor 8), clamped to ``max_seq`` like the engine's
+    ``_bucket_len``.  With a power-of-two budget >= 8 (the deployment
+    default) the executed width never exceeds the budget itself."""
+    b = 8
+    while b < n_tokens:
+        b *= 2
+    return min(b, max_seq)
+
+
 @dataclass
 class _SlotState:
     request: Request
@@ -805,6 +816,15 @@ class ContinuousEngine:
     draft+input tokens verified) — the benchmark and the property
     suite gate ``prefill <= budget`` and ``decode <= n_slots`` on
     them (verify adds at most ``n_slots * (draft_k + 1)``).
+    ``counters()`` snapshots the cumulative work counters the tick
+    keeps beside ``prefill_tokens_total``: decode rows run against rows
+    holding a sequence, live KV pages against the pages the kernel's
+    grid visits, padded chunk width against real prompt tokens.
+
+    Under a ``jax.profiler`` session each ``step`` is an ``engine.step``
+    span and each blocking device-to-host read of its tokens (and
+    final logits) an ``engine.read`` span, on the device trace's clock;
+    without a session the spans cost a few no-op calls a tick.
     """
 
     FAMILIES = ("dense", "moe", "hybrid", "ssm")
@@ -859,20 +879,30 @@ class ContinuousEngine:
                                           prefix_cache=prefix_cache,
                                           mesh=mesh,
                                           logical_map=self.logical_map)
+            # named functions name the programs in a device trace
+            # (``jit_decode_step``, ``jit_prefill_chunk``, ``jit_prefill``)
+            def decode_step(p, c, t, pos, bt):
+                return T.decode_step(p, cfg, c, t, pos, block_tables=bt)
+
+            def prefill_chunk(p, c, t, nv, off, bt, cap):
+                return T.prefill_chunk(p, cfg, c, t, nv, off, bt,
+                                       moe_capacity=cap)
+
             self._decode = _cached_jit(
-                ("cont_decode_paged", cfg, mkey), lambda: wrap(jax.jit(
-                    lambda p, c, t, pos, bt: T.decode_step(
-                        p, cfg, c, t, pos, block_tables=bt))))
+                ("cont_decode_paged", cfg, mkey),
+                lambda: wrap(jax.jit(decode_step)))
             self._chunk = _cached_jit(
-                ("prefill_chunk", cfg, mkey), lambda: wrap(jax.jit(
-                    lambda p, c, t, nv, off, bt, cap: T.prefill_chunk(
-                        p, cfg, c, t, nv, off, bt, moe_capacity=cap),
-                    static_argnums=(6,))))
+                ("prefill_chunk", cfg, mkey),
+                lambda: wrap(jax.jit(prefill_chunk, static_argnums=(6,))))
         else:
             self.slots = SlotManager(cfg, n_slots, max_seq)
+
+            def decode_step(p, c, t, pos):
+                return T.decode_step(p, cfg, c, t, pos)
+
             self._decode = _cached_jit(
-                ("cont_decode", cfg, mkey), lambda: wrap(jax.jit(
-                    lambda p, c, t, pos: T.decode_step(p, cfg, c, t, pos))))
+                ("cont_decode", cfg, mkey),
+                lambda: wrap(jax.jit(decode_step)))
         self.queue = RequestQueue(max_batch=n_slots,
                                   capacity=queue_capacity)
         self.draft_k = draft_k
@@ -884,6 +914,11 @@ class ContinuousEngine:
         self.last_tick_verify_tokens = 0
         self.prefill_tokens_total = 0         # prompt tokens actually run
         #                                       (prefix-cache hits charge 0)
+        self.chunk_bucket_tokens_total = 0    # padded width of their chunks
+        self.decode_rows_total = 0            # decode rows run (n_slots each)
+        self.decode_tokens_total = 0          # rows holding a decoding seq
+        self.decode_live_pages_total = 0      # pages those rows read (paged)
+        self.decode_grid_pages_total = 0      # pages the kernel grid visits
         self.spec_verify_passes = 0           # one-chunk draft verifications
         self.spec_drafted_total = 0           # draft tokens verified
         self.spec_accepted_total = 0          # draft tokens accepted
@@ -892,13 +927,15 @@ class ContinuousEngine:
         self._spent_this_tick = 0
         self._verify_this_tick = 0
         self._tick_budget_left = self._budget()
+
+        def prefill(p, t, cap):
+            return T.forward(p, cfg, {"tokens": t}, moe_drop_free=True,
+                             moe_capacity=cap, return_cache=True,
+                             remat=False)
+
         self._prefill = _cached_jit(
-            ("cont_prefill", cfg, mkey), lambda: wrap(jax.jit(
-                lambda p, t, cap: T.forward(p, cfg, {"tokens": t},
-                                            moe_drop_free=True,
-                                            moe_capacity=cap,
-                                            return_cache=True, remat=False),
-                static_argnums=(2,))))
+            ("cont_prefill", cfg, mkey),
+            lambda: wrap(jax.jit(prefill, static_argnums=(2,))))
 
     def clone_fresh(self) -> "ContinuousEngine":
         """A new engine with the same config/params/layout knobs and
@@ -986,26 +1023,17 @@ class ContinuousEngine:
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :S] = req.prompt
         logits, pcache = self._run_prefill(toks)
-        first = int(jnp.argmax(logits[0, S - 1]))
+        with jax.profiler.TraceAnnotation("engine.read"):
+            first = int(jnp.argmax(logits[0, S - 1]))
+            last = np.asarray(logits[0, S - 1], np.float32)
         st = _SlotState(request=req, pos=S, next_tok=first, emitted=[first],
                         admitted_step=self.clock,
-                        first_token_step=self.clock,
-                        last_logits=np.asarray(logits[0, S - 1], np.float32))
+                        first_token_step=self.clock, last_logits=last)
         self.slots.place(slot, pcache, st)
         if len(st.emitted) >= req.max_new:    # max_new == 1: done at prefill
             self._finish(slot)
 
     # -- chunked prefill (paged layout) -------------------------------------
-    def _chunk_bucket(self, C: int) -> int:
-        """Jit bucket for a chunk of C real tokens: next power of two
-        (floor 8), clamped to max_seq like ``_bucket_len``.  With a
-        power-of-two budget >= 8 (the deployment default) the executed
-        width never exceeds the budget itself."""
-        b = 8
-        while b < C:
-            b *= 2
-        return min(b, self.max_seq)
-
     def _run_chunk(self, toks: np.ndarray, n_valid: int, pos_offset: int,
                    bt: np.ndarray):
         """One jitted chunk forward; MoE archs run the dynamic
@@ -1033,7 +1061,7 @@ class ContinuousEngine:
         while st.phase == PREFILLING and self._tick_budget_left > 0:
             off = req.prefill_pos
             C = int(min(self._tick_budget_left, S - off))
-            Cb = self._chunk_bucket(C)
+            Cb = chunk_bucket(C, self.max_seq)
             toks = np.zeros((1, Cb), np.int32)
             toks[0, :C] = req.prompt[off:off + C]
             self.slots.grow_for_chunk(slot, off + C)
@@ -1044,13 +1072,15 @@ class ContinuousEngine:
             self._tick_budget_left -= C
             self._spent_this_tick += C
             self.prefill_tokens_total += C
+            self.chunk_bucket_tokens_total += Cb
             if req.prefill_pos >= S:
-                first = int(jnp.argmax(logits[0, C - 1]))
+                with jax.profiler.TraceAnnotation("engine.read"):
+                    first = int(jnp.argmax(logits[0, C - 1]))
+                    st.last_logits = np.asarray(logits[0, C - 1], np.float32)
                 st.phase = DECODING
                 st.next_tok = first
                 st.emitted = [first]
                 st.first_token_step = self.clock
-                st.last_logits = np.asarray(logits[0, C - 1], np.float32)
                 self.slots.note_prefill_complete(slot)
                 if len(st.emitted) >= req.max_new:
                     self._finish(slot)
@@ -1104,14 +1134,15 @@ class ContinuousEngine:
             st.drafts = []
             return False
         C = k + 1
-        Cb = self._chunk_bucket(C)
+        Cb = chunk_bucket(C, self.max_seq)
         toks = np.zeros((1, Cb), np.int32)
         toks[0, 0] = st.next_tok
         toks[0, 1:C] = st.drafts[:k]
         self.slots.grow_for_chunk(slot, st.pos + C)
         logits, self.slots.cache = self._run_chunk(
             toks, C, st.pos, self.slots.chunk_block_table(slot))
-        preds = np.asarray(jnp.argmax(logits[0, :C], -1))
+        with jax.profiler.TraceAnnotation("engine.read"):
+            preds = np.asarray(jnp.argmax(logits[0, :C], -1))
         n_ok = 0
         while n_ok < k and int(preds[n_ok]) == st.drafts[n_ok]:
             n_ok += 1
@@ -1130,7 +1161,8 @@ class ContinuousEngine:
         self.spec_accepted_total += n_ok
         self._verify_this_tick += C
         if len(st.emitted) >= req.max_new:
-            st.last_logits = np.asarray(logits[0, n_ok], np.float32)
+            with jax.profiler.TraceAnnotation("engine.read"):
+                st.last_logits = np.asarray(logits[0, n_ok], np.float32)
             self._finish(slot)
         return True
 
@@ -1153,6 +1185,22 @@ class ContinuousEngine:
                 "drafted": self.spec_drafted_total,
                 "accepted": self.spec_accepted_total,
                 "draft_streams_dropped": self.spec_draft_streams_dropped}
+
+    COUNTERS = ("prefill_tokens_total", "chunk_bucket_tokens_total",
+                "decode_rows_total", "decode_tokens_total",
+                "decode_live_pages_total", "decode_grid_pages_total")
+
+    def counters(self) -> Dict[str, int]:
+        """Snapshot of the cumulative work counters: prompt tokens run
+        and the padded chunk width they ran in; the rows decode launches
+        ran (``n_slots`` each), the rows holding a decoding sequence, the
+        KV pages those rows read and the pages the paged kernel's grid
+        visits (both 0 under the contiguous layout).  Observability,
+        not serving state: of these the scheduler's checkpoint carries
+        only ``prefill_tokens_total``, and ``clone_fresh`` starts every
+        one at 0.  So a ratio of two counters' growth holds only over a
+        window that lies wholly after the last restore."""
+        return {k: int(getattr(self, k)) for k in self.COUNTERS}
 
     def _finish(self, slot: int) -> None:
         st = self.slots.states[slot]
@@ -1212,26 +1260,41 @@ class ContinuousEngine:
         if not decoding:
             return
         toks, pos = self.slots.decode_inputs(skip)
+        self.decode_rows_total += self.slots.n_slots
+        self.decode_tokens_total += len(decoding)
         if self.kv_layout == "paged":
             self.slots.ensure_write_pages(skip)
+            bt = self.slots.block_tables(skip)
+            ps = self.slots.page_size
+            # each decoding row reads ceil(kv_len / page_size) pages,
+            # kv_len = pos + 1; the kernel's grid visits every entry of bt
+            self.decode_live_pages_total += int(np.sum(
+                (pos[decoding] + ps) // ps))
+            self.decode_grid_pages_total += bt.size
             logits, self.slots.cache = self._decode(
                 self.params, self.slots.cache, jnp.asarray(toks),
-                jnp.asarray(pos), jnp.asarray(self.slots.block_tables(skip)))
+                jnp.asarray(pos), jnp.asarray(bt))
         else:
             logits, self.slots.cache = self._decode(
                 self.params, self.slots.cache, jnp.asarray(toks),
                 jnp.asarray(pos))
-        nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
+        states = self.slots.states
+        finishing = [s for s in decoding if len(states[s].emitted) + 1
+                     >= states[s].request.max_new]
+        with jax.profiler.TraceAnnotation("engine.read"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0], -1))
+            # fetch the final-step logits row only for sequences
+            # finishing now (confidence routing); copying every step
+            # would put a (n_slots, V) host transfer on the hot path
+            last = {s: np.asarray(logits[s, 0], np.float32)
+                    for s in finishing}
         for slot in decoding:
-            st = self.slots.states[slot]
+            st = states[slot]
             st.emitted.append(int(nxt[slot]))
             st.next_tok = int(nxt[slot])
             st.pos += 1
-            if len(st.emitted) >= st.request.max_new:
-                # fetch the final-step logits row only for sequences
-                # finishing now (confidence routing); copying every step
-                # would put a (n_slots, V) host transfer on the hot path
-                st.last_logits = np.asarray(logits[slot, 0], np.float32)
+            if slot in last:
+                st.last_logits = last[slot]
                 self._finish(slot)
 
     def _unified_step(self) -> None:
@@ -1261,10 +1324,11 @@ class ContinuousEngine:
         finished during this step.  (``serving.scheduler`` drives
         ``_admit_arrivals`` / ``_unified_step`` separately to interpose
         preemption.)"""
-        before = len(self.finish_order)
-        self._admit_arrivals()
-        self._unified_step()
-        return self.finish_order[before:]
+        with jax.profiler.TraceAnnotation("engine.step"):
+            before = len(self.finish_order)
+            self._admit_arrivals()
+            self._unified_step()
+            return self.finish_order[before:]
 
     def run(self, requests: Optional[List[Request]] = None
             ) -> Dict[int, RequestResult]:

@@ -452,8 +452,9 @@ class PreemptiveScheduler:
         before = len(eng.finish_order)
         self._drain_store_evictions()
         if decode:
-            self._admit_by_priority()
-            eng._unified_step()
+            with jax.profiler.TraceAnnotation("engine.step"):
+                self._admit_by_priority()
+                eng._unified_step()
         else:
             eng._idle_tick()                   # compute yielded
         finished = eng.finish_order[before:]

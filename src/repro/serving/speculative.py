@@ -34,7 +34,7 @@ from repro.config import ModelConfig
 from repro.core.link import payload_bytes_draft
 from repro.core.telemetry import Ledger
 from repro.serving.batching import Request
-from repro.serving.engine import DECODING, ContinuousEngine
+from repro.serving.engine import DECODING, ContinuousEngine, chunk_bucket
 from repro.serving.paging import pages_for
 
 
@@ -132,7 +132,7 @@ class SpeculativeDecoder:
         eng = self.draft
         st = eng.slots.states[slot]
         n = len(toks)
-        Cb = eng._chunk_bucket(n)
+        Cb = chunk_bucket(n, eng.max_seq)
         buf = np.zeros((1, Cb), np.int32)
         buf[0, :n] = toks
         st.pos = int(pos)

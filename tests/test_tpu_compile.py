@@ -11,6 +11,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -73,7 +75,11 @@ def test_paged_decode_kernel_compiles(one_chip, arch, dtype):
         _spec((B, H, D), dtype, one_chip), pool, pool,
         _spec((B, max_pages), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip)).compile()
-    assert PALLAS_OP in compiled.as_text()
+    text = compiled.as_text()
+    assert PALLAS_OP in text
+    # the op's name in a device trace, which paged_attn_roofline reads
+    assert re.search(r"%paged_decode_attention_kernel\.\d+ = \S+ custom-call\(",
+                     text)
 
 
 def test_confidence_gate_kernel_compiles(one_chip):
