@@ -918,7 +918,7 @@ class ContinuousEngine:
         self.decode_rows_total = 0            # decode rows run (n_slots each)
         self.decode_tokens_total = 0          # rows holding a decoding seq
         self.decode_live_pages_total = 0      # pages those rows read (paged)
-        self.decode_grid_pages_total = 0      # pages the kernel grid visits
+        self.decode_grid_pages_total = 0      # block-table entries
         self.spec_verify_passes = 0           # one-chunk draft verifications
         self.spec_drafted_total = 0           # draft tokens verified
         self.spec_accepted_total = 0          # draft tokens accepted
@@ -1266,8 +1266,8 @@ class ContinuousEngine:
             self.slots.ensure_write_pages(skip)
             bt = self.slots.block_tables(skip)
             ps = self.slots.page_size
-            # each decoding row reads ceil(kv_len / page_size) pages,
-            # kv_len = pos + 1; the kernel's grid visits every entry of bt
+            # each decoding row reads ceil(kv_len / page_size) of its bt
+            # entries (kv_len = pos + 1): the kernel stops at the last one
             self.decode_live_pages_total += int(np.sum(
                 (pos[decoding] + ps) // ps))
             self.decode_grid_pages_total += bt.size

@@ -65,26 +65,59 @@ def test_decode_attention_kernel_unaligned_cache(B, S, H, Hkv, D, S_odd):
     np.testing.assert_allclose(out, want, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.parametrize("B,H,Hkv,D,ps,max_bt", [
-    (2, 4, 2, 64, 16, 4),
-    (3, 8, 1, 32, 8, 6),
-    (1, 2, 2, 128, 16, 2),
-    (3, 15, 5, 64, 16, 4),                        # smollm-360m heads (g=3)
-    (2, 8, 4, 48, 16, 3),                         # tiansuan-ground heads
+def _paged_case(B, H, Hkv, D, ps, max_bt, lens=None, id=None):
+    return pytest.param(B, H, Hkv, D, ps, max_bt, lens,
+                        id=id or f"{B}-{H}-{Hkv}-{D}-{ps}-{max_bt}")
+
+
+def _engine_tables(lens, ps, max_bt, rng):
+    """Block tables as the engine builds them: each row's first
+    ceil(kv_len / ps) entries on distinct pool pages, the rest on the
+    scratch page 0; a row with kv_len 1 (a non-decoding row) is page 0
+    throughout."""
+    bt = np.zeros((len(lens), max_bt), np.int32)
+    free = iter(rng.permutation(np.arange(1, len(lens) * max_bt + 1)))
+    for b, n in enumerate(lens):
+        if n > 1:
+            bt[b, :-(-n // ps)] = [next(free) for _ in range(-(-n // ps))]
+    return bt
+
+
+@pytest.mark.parametrize("B,H,Hkv,D,ps,max_bt,lens", [
+    _paged_case(2, 4, 2, 64, 16, 4),
+    _paged_case(3, 8, 1, 32, 8, 6),
+    _paged_case(1, 2, 2, 128, 16, 2),
+    _paged_case(3, 15, 5, 64, 16, 4),             # smollm-360m heads (g=3)
+    _paged_case(2, 8, 4, 48, 16, 3),              # tiansuan-ground heads
+    # 8-page blocks below (128 positions); 20 entries leave a last
+    # block of 4, and row 0 ends inside it
+    _paged_case(2, 4, 2, 64, 16, 20, (300, 17), id="table-not-block-multiple"),
+    _paged_case(3, 4, 2, 64, 16, 40, (256, 512, 255), id="block-boundary"),
+    _paged_case(2, 8, 4, 48, 16, 48, (700, 33), id="several-blocks"),
+    _paged_case(4, 15, 5, 64, 16, 24, (1, 290, 1, 1), id="idle-rows"),
+    _paged_case(3, 15, 5, 64, 16, 128, (2048, 1100, 1),
+                id="smollm-360m-served-width"),
+    _paged_case(2, 20, 20, 128, 16, 128, (1500, 1),
+                id="qwen1.5-4b-served-width"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_kernel(B, H, Hkv, D, ps, max_bt, dtype):
+def test_paged_decode_attention_kernel(B, H, Hkv, D, ps, max_bt, lens, dtype):
     """Interpret-mode paged kernel vs the ref.py gather reference, with
-    shuffled (non-contiguous) block tables and ragged lengths."""
+    shuffled (non-contiguous) block tables and ragged lengths: random
+    ones, or given ones on engine-built tables."""
     n_pages = B * max_bt + 1                      # + scratch page 0
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
     kp = jax.random.normal(ks[1], (n_pages, ps, Hkv, D), dtype)
     vp = jax.random.normal(ks[2], (n_pages, ps, Hkv, D), dtype)
     rng = np.random.default_rng(0)
-    bt = jnp.asarray(rng.permutation(np.arange(1, n_pages))
-                     .reshape(B, max_bt), jnp.int32)
-    lens = jnp.asarray(rng.integers(1, max_bt * ps + 1, B), jnp.int32)
+    if lens is None:
+        bt = jnp.asarray(rng.permutation(np.arange(1, n_pages))
+                         .reshape(B, max_bt), jnp.int32)
+        lens = jnp.asarray(rng.integers(1, max_bt * ps + 1, B), jnp.int32)
+    else:
+        bt = jnp.asarray(_engine_tables(lens, ps, max_bt, rng))
+        lens = jnp.asarray(lens, jnp.int32)
     got = ops.paged_decode_attention(q, kp, vp, bt, lens)
     want = ref.paged_decode_attention_ref(q, kp, vp, bt, lens)
     np.testing.assert_allclose(got.astype(jnp.float32),
@@ -97,6 +130,50 @@ def test_paged_decode_attention_kernel(B, H, Hkv, D, ps, max_bt, dtype):
                                ref.decode_attention_ref(
                                    q, kg, vg, lens).astype(jnp.float32),
                                atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_attention_reads_no_dead_page(dtype):
+    """Pages that no row lists inside ceil(kv_len / page_size) hold NaN,
+    and every dead table entry points at one: the output stays finite
+    and matches the reference on a clean pool.  Positions past kv_len in
+    each last live page hold huge finite values, then NaN: the output
+    does not change by a single bit."""
+    B, H, Hkv, D, ps, max_bt = 3, 15, 5, 64, 16, 40
+    lens = np.array([300, 256, 17])
+    n_live = -(-lens // ps)
+    n_pages = 1 + int(n_live.sum()) + 8
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, H, D), dtype)
+    kp = jax.random.normal(ks[1], (n_pages, ps, Hkv, D), dtype)
+    vp = jax.random.normal(ks[2], (n_pages, ps, Hkv, D), dtype)
+    rng = np.random.default_rng(1)
+    pages = rng.permutation(np.arange(1, n_pages))
+    live, dead = pages[:n_live.sum()], pages[n_live.sum():]
+    bt = rng.choice(dead, (B, max_bt)).astype(np.int32)
+    starts = np.concatenate([[0], np.cumsum(n_live)])
+    for b in range(B):
+        bt[b, :n_live[b]] = live[starts[b]:starts[b + 1]]
+    bt, kv_len = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+    want = ref.paged_decode_attention_ref(q, kp, vp, bt, kv_len)
+
+    poisoned = jnp.zeros(n_pages, bool).at[jnp.asarray(dead)].set(True)
+    nan = lambda x: jnp.where(poisoned[:, None, None, None], jnp.nan, x)
+    got = ops.paged_decode_attention(q, nan(kp), nan(vp), bt, kv_len)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               want.astype(jnp.float32), **_tol(dtype))
+
+    for k_tail, v_tail in ((3e38, -3e38), (jnp.nan, jnp.nan)):
+        kt, vt = nan(kp), nan(vp)
+        for b in range(B):
+            last = int(bt[b, n_live[b] - 1])
+            tail = lens[b] - (n_live[b] - 1) * ps
+            kt = kt.at[last, tail:].set(k_tail)
+            vt = vt.at[last, tail:].set(v_tail)
+        tailed = ops.paged_decode_attention(q, kt, vt, bt, kv_len)
+        np.testing.assert_array_equal(np.asarray(tailed.astype(jnp.float32)),
+                                      np.asarray(got.astype(jnp.float32)))
 
 
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [

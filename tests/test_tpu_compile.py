@@ -65,11 +65,15 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("arch", sorted(PAGED_WIDTHS))
+@pytest.mark.parametrize("arch,max_pages", [
+    *[pytest.param(a, 32, id=a) for a in sorted(PAGED_WIDTHS)],
+    # the served table width: max_seq 2048 over 16-position pages
+    *[pytest.param(a, 128, id=f"{a}-128-pages") for a in sorted(PAGED_WIDTHS)],
+])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_paged_decode_kernel_compiles(one_chip, arch, dtype):
+def test_paged_decode_kernel_compiles(one_chip, arch, max_pages, dtype):
     B, H, Hkv, D = PAGED_WIDTHS[arch]
-    ps, max_pages, n_pages = 16, 32, 193
+    ps, n_pages = 16, 193
     pool = _spec((n_pages, ps, Hkv, D), dtype, one_chip)
     compiled = paged_decode_attention_kernel.lower(
         _spec((B, H, D), dtype, one_chip), pool, pool,
