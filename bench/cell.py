@@ -14,9 +14,10 @@ The run, in order:
    uses, and the ramp until every request admitted at the first tick
    has its first token;
 2. the window: ``seconds`` of serving (with ``trace``, under the
-   profiler, the harness's spans around each tick and each decode and
-   chunk launch);
-3. the device's peak memory is read and the engine's state freed;
+   profiler, the harness's span around each tick, and a record of the
+   work each decode and chunk launch carries);
+3. the peak memory of each of the cell's devices is read and the
+   engine's state freed;
 4. the check: the float32 reference runs over a sample of the requests
    finished in the window, one from each slot that finished one
    (``bench/reference.py``).
@@ -65,7 +66,8 @@ class Run:
     """What the metric readers see."""
     cell: dict
     dims: dict
-    peak: dict | None
+    peak: dict | None                # one chip's published peaks
+    chips: int                       # the cell's chips
     seconds: float
     setup_s: float
     window: tuple
@@ -158,18 +160,24 @@ class CompileCounter:
 
 # -- the engine -------------------------------------------------------------
 
-def build_engine(spec: dict, seed: int):
+def build_engine(spec: dict, seed: int, chips: int):
+    """The engine over the cell's weights.  A cell on one chip runs on the
+    first device.  A cell on several chips is served tensor-parallel over
+    the first ``chips`` devices (the program's serving mesh, every device
+    on the ``model`` axis): its weights are made in place on them and the
+    engine shards its pool and steps over them."""
     import jax
 
     from model import model_config, served_params
+    from repro.launch.mesh import make_serving_mesh
     from repro.serving.engine import ContinuousEngine
 
     cfg = model_config(spec)
-    params = served_params(spec, seed)
+    mesh = None if chips == 1 else make_serving_mesh(chips)
+    params = served_params(spec, seed, mesh)
     jax.block_until_ready(params)
-    eng = ContinuousEngine(cfg, params, queue_capacity=None, kv_layout="paged",
-                           **spec["engine"])
-    return eng
+    return ContinuousEngine(cfg, params, queue_capacity=None, kv_layout="paged",
+                            mesh=mesh, **spec["engine"])
 
 
 def warm_up(eng, spec: dict) -> None:
@@ -228,15 +236,11 @@ class Observer:
 
 
 class Tracer:
-    """With ``--trace 1``: host spans around each decode and chunk launch,
-    and the work each launch carries."""
+    """With ``--trace 1``: the work each decode and chunk launch carries."""
 
     def __init__(self, eng):
-        import jax
-
         self.decode_calls, self.chunk_calls = [], []
         self.recording = False
-        self._ann = jax.profiler.TraceAnnotation
         decode, run_chunk = eng._decode, eng._run_chunk
         states = eng.slots.states
 
@@ -245,14 +249,12 @@ class Tracer:
                 self.decode_calls.append(
                     [st.pos + 1 for st in states
                      if st is not None and st.phase == "decode"])
-            with self._ann("bench.decode"):
-                return decode(*args, **kw)
+            return decode(*args, **kw)
 
         def traced_chunk(toks, n_valid, pos_offset, bt):
             if self.recording:
                 self.chunk_calls.append((int(pos_offset), int(n_valid)))
-            with self._ann("bench.chunk"):
-                return run_chunk(toks, n_valid, pos_offset, bt)
+            return run_chunk(toks, n_valid, pos_offset, bt)
 
         eng._decode = traced_decode
         eng._run_chunk = traced_chunk
@@ -289,7 +291,7 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool, *,
     m = dims(spec)
     counter = CompileCounter()
 
-    eng = build_engine(spec, seed)
+    eng = build_engine(spec, seed, cell["chips"])
     warm_up(eng, spec)
     if stage is not None:
         stage(eng)
@@ -344,10 +346,10 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool, *,
           f"{compiles}", file=sys.stderr)
 
     # -- memory, then free the program's state ------------------------------
-    peak_bytes = None
+    per_device = []
     if device is not None:
-        stats = jax.devices()[0].memory_stats() or {}
-        peak_bytes = stats.get("peak_bytes_in_use")
+        per_device = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                      for d in jax.devices()[:cell["chips"]]]
     logs = list(obs.logs.values())
     free_engine(eng)
     del eng
@@ -357,10 +359,12 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float, trace: bool, *,
     if device is not None:
         from counts import peaks
         peak = peaks(device["kind"])
-    run = Run(cell=cell, dims=m, peak=peak, seconds=seconds,
+    run = Run(cell=cell, dims=m, peak=peak, chips=cell["chips"], seconds=seconds,
               setup_s=setup_s, window=(t0, t1), ticks=ticks, requests=logs)
     out_dev = dict(device or {"platform": "none", "kind": "none", "count": 0})
-    out_dev["memory_peak_bytes"] = peak_bytes
+    out_dev["memory_peak_bytes"] = (max(per_device) if per_device
+                                    and None not in per_device else None)
+    out_dev["memory_peak_bytes_per_device"] = per_device
     breakdown = None
     if trace:
         from devtrace import breakdown as make_breakdown, device_busy, read_xplane

@@ -21,26 +21,19 @@ def peaks(device_kind: str) -> dict:
     return table["devices"][device_kind]
 
 
-def matmul_params(m: dict) -> int:
-    """Weights that every token multiplies: all layers' projections and
-    the output head (embedding lookups multiply nothing)."""
-    d, H, Hkv, D, ff = m["d"], m["H"], m["Hkv"], m["D"], m["ff"]
-    per_layer = d * H * D + 2 * d * Hkv * D + H * D * d + 3 * d * ff
-    return m["L"] * per_layer + d * m["V"]
-
-
 def token_flops(m: dict, context: int) -> float:
     """Forward FLOPs of one token that attends to ``context`` positions
-    (itself included): 2 per weight, and 4 H D per attended position per
-    layer (scores and the weighted sum of values)."""
-    return 2.0 * matmul_params(m) + 4.0 * m["L"] * m["H"] * m["D"] * context
+    (itself included): 2 per weight it multiplies (``matmul_params``, from
+    the configuration's family module), and 4 H D per attended position
+    per layer (scores and the weighted sum of values)."""
+    return 2.0 * m["matmul_params"] + 4.0 * m["L"] * m["H"] * m["D"] * context
 
 
 def chunk_flops(m: dict, pos_offset: int, n_valid: int) -> float:
     """Forward FLOPs of a prompt chunk: positions pos_offset .. +n_valid,
     each attending causally to everything before it and itself."""
     ctx = n_valid * pos_offset + n_valid * (n_valid + 1) / 2
-    return 2.0 * matmul_params(m) * n_valid + 4.0 * m["L"] * m["H"] * m["D"] * ctx
+    return 2.0 * m["matmul_params"] * n_valid + 4.0 * m["L"] * m["H"] * m["D"] * ctx
 
 
 def paged_attention_work(m: dict, kv_lens) -> tuple:
@@ -56,9 +49,9 @@ def paged_attention_work(m: dict, kv_lens) -> tuple:
     return flops * L, bytes_ * L
 
 
-def least_time(flops: float, bytes_: float, peak: dict) -> tuple:
+def least_time(flops: float, bytes_: float, peak: dict, chips: int) -> tuple:
     """(seconds, bound) of the roofline: the larger of the compute and the
-    memory time at the chip's published peaks."""
-    tc = flops / peak["bf16_flops_per_s"]
-    tm = bytes_ / peak["hbm_bytes_per_s"]
+    memory time at ``chips`` times one chip's published peaks."""
+    tc = flops / (peak["bf16_flops_per_s"] * chips)
+    tm = bytes_ / (peak["hbm_bytes_per_s"] * chips)
     return (tc, "compute") if tc >= tm else (tm, "memory")

@@ -8,16 +8,14 @@ the start of the trace).  What is kept:
   (name, start, end).  Loops nest their bodies' operations, so busy time
   is a union of intervals and an operation's own time is its duration
   less its children's.
-* ``modules``: program executions of each chip's ``XLA Modules`` line.
-* ``execs``: host launches of programs (``PJRT_LoadedExecutable_Execute``),
-  one per module execution and in the same order.
+* ``modules``: program executions of each chip's ``XLA Modules`` line,
+  named ``<program>(<fingerprint>)``: the engine's jitted steps are named
+  functions, so its decode step runs as ``jit_decode_step`` and its
+  prompt chunks as ``jit_prefill_chunk``.
 * ``spans``: the harness's own ``TraceAnnotation`` spans (``bench.*``).
 * ``python``: the other host events of the harness's own thread (Python
   calls, where the profiler's Python tracer records them, and JAX's
-  dispatches), to name idle gaps.
-
-A module is attributed to the engine's decode step or chunk step when its
-launch lies inside the harness's ``bench.decode`` or ``bench.chunk`` span.
+  dispatches, and the engine's own spans), to name idle gaps.
 """
 from __future__ import annotations
 
@@ -29,7 +27,6 @@ import re
 from collections import defaultdict
 from dataclasses import dataclass, field
 
-EXEC_EVENT = "PJRT_LoadedExecutable_Execute"
 SPAN_PREFIX = "bench."
 NAMED_GAPS = 2000          # longest idle gaps named by the host span
 
@@ -38,21 +35,19 @@ NAMED_GAPS = 2000          # longest idle gaps named by the host span
 class Trace:
     ops: dict = field(default_factory=dict)       # chip -> [(name, s, e)]
     modules: dict = field(default_factory=dict)   # chip -> [(name, s, e)]
-    execs: list = field(default_factory=list)     # [start]
     spans: list = field(default_factory=list)     # [(name, s, e)]
     python: list = field(default_factory=list)    # [(name, s, e)]
 
     def to_json(self) -> dict:
-        return {"ops": self.ops, "modules": self.modules, "execs": self.execs,
-                "spans": self.spans, "python": self.python}
+        return {"ops": self.ops, "modules": self.modules, "spans": self.spans,
+                "python": self.python}
 
     @classmethod
     def from_json(cls, d: dict) -> "Trace":
         tup = lambda xs: [tuple(x) for x in xs]          # noqa: E731
         return cls(ops={k: tup(v) for k, v in d["ops"].items()},
                    modules={k: tup(v) for k, v in d["modules"].items()},
-                   execs=list(d["execs"]), spans=tup(d["spans"]),
-                   python=tup(d["python"]))
+                   spans=tup(d["spans"]), python=tup(d["python"]))
 
     # -- windows -----------------------------------------------------------
     def span_list(self, name: str) -> list:
@@ -96,13 +91,11 @@ def read_xplane(log_dir: str) -> Trace:
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 evs = [(e.name, e.start_ns, e.end_ns) for e in line.events]
-                tr.execs.extend(s for n, s, _ in evs if n == EXEC_EVENT)
                 spans = [x for x in evs if x[0].startswith(SPAN_PREFIX)]
                 if spans:               # the harness's own (Python) thread
                     tr.spans.extend(spans)
                     tr.python.extend(x for x in evs
                                      if not x[0].startswith(SPAN_PREFIX))
-    tr.execs.sort()
     tr.spans.sort(key=lambda x: x[1])
     tr.python.sort(key=lambda x: x[1])
     for v in tr.ops.values():
@@ -184,64 +177,40 @@ def self_times(events) -> list:
 
 # -- programs ---------------------------------------------------------------
 
-def module_kinds(trace: Trace, chip: str) -> list | None:
-    """[(kind, start, end)] for each module execution on ``chip``: kind is
-    the harness span (``decode``, ``chunk``) whose launch it is, else None.
-    A span can launch small programs besides the step (the conversion of
-    its scalar arguments); the longest module a span launched is its
-    step.  Returns None where launches and executions do not pair up."""
-    mods = trace.modules.get(chip, [])
+def program_name(module: str) -> str:
+    """``jit_decode_step(8264366344727610541)`` -> ``jit_decode_step``."""
+    return module.split("(", 1)[0]
+
+
+def program_runs(trace: Trace, program: str) -> list:
+    """For each chip that ran ``program`` in the window, (seconds of device
+    time, executions) of its modules of that name."""
     lo, hi = trace.window()
-    mods = [m for m in mods if lo <= m[1] <= hi]
-    execs = [t for t in trace.execs if lo <= t <= hi]
-    if not mods or len(execs) != len(mods):
+    out = []
+    for mods in trace.modules.values():
+        t = [e - s for n, s, e in mods
+             if lo <= s <= hi and program_name(n) == program]
+        if t:
+            out.append((sum(t) * 1e-9, len(t)))
+    return out
+
+
+def ms_per_execution(trace: Trace, program: str) -> float | None:
+    """Device milliseconds per execution of ``program``, averaged over
+    chips; None where it did not run."""
+    runs = program_runs(trace, program)
+    if not runs:
         return None
-    calls = sorted([(s, e, n[len(SPAN_PREFIX):]) for n, s, e in trace.spans
-                    if n in ("bench.decode", "bench.chunk")])
-    step = {}                       # span index -> longest module index
-    j = 0
-    for i, (t, (_, s, e)) in enumerate(zip(execs, mods)):
-        while j < len(calls) and calls[j][1] < t:
-            j += 1
-        if j < len(calls) and calls[j][0] <= t:
-            k = step.get(j)
-            if k is None or e - s > mods[k][2] - mods[k][1]:
-                step[j] = i
-    kinds = {i: calls[j][2] for j, i in step.items()}
-    return [(kinds.get(i), s, e) for i, (_, s, e) in enumerate(mods)]
+    return sum(t / n for t, n in runs) / len(runs) * 1e3
 
 
-def program_time(trace: Trace, kind: str) -> tuple | None:
-    """(seconds of device time, executions) of the ``kind`` programs,
-    averaged over chips; None when the trace cannot attribute them."""
-    per_chip = []
-    for chip in trace.modules:
-        mk = module_kinds(trace, chip)
-        if mk is None:
-            return None
-        sel = [(s, e) for k, s, e in mk if k == kind]
-        per_chip.append((sum(e - s for s, e in sel) * 1e-9, len(sel)))
-    if not per_chip:
+def us_per_token(trace: Trace, program: str, tokens: int) -> float | None:
+    """Device microseconds of ``program`` (averaged over chips) per token
+    of the ``tokens`` it ran; None where it did not run or ran none."""
+    runs = program_runs(trace, program)
+    if not runs or tokens == 0:
         return None
-    return (sum(p[0] for p in per_chip) / len(per_chip), per_chip[0][1])
-
-
-def ms_per_execution(trace: Trace, kind: str) -> float | None:
-    """Device milliseconds per execution of the ``kind`` program."""
-    pt = program_time(trace, kind)
-    if pt is None or pt[1] == 0:
-        return None
-    return pt[0] / pt[1] * 1e3
-
-
-def us_per_token(trace: Trace, chunk_calls) -> float | None:
-    """Device microseconds of the chunk programs per real prompt token
-    they ran; ``chunk_calls`` are the launches' (pos_offset, n_valid)."""
-    pt = program_time(trace, "chunk")
-    tokens = sum(n for _, n in chunk_calls)
-    if pt is None or pt[1] == 0 or tokens == 0:
-        return None
-    return pt[0] / tokens * 1e6
+    return sum(t for t, _ in runs) / len(runs) / tokens * 1e6
 
 
 def kernel_time(trace: Trace, kernel: str) -> float | None:

@@ -1,7 +1,7 @@
 """Model FLOP/s utilization of the whole step: forward FLOPs of every real
 token run in the traced window (decode tokens at their kv_len, prompt
 chunk tokens at their positions), over the window's seconds times the
-chip's published bf16 peak."""
+cell's chips times one chip's published bf16 peak."""
 from counts import chunk_flops, token_flops
 
 
@@ -11,4 +11,5 @@ def read(run):
     lo, hi = run.trace.window()
     flops = sum(token_flops(run.dims, n) for c in run.decode_calls for n in c)
     flops += sum(chunk_flops(run.dims, off, n) for off, n in run.chunk_calls)
-    return flops / ((hi - lo) * 1e-9 * run.peak["bf16_flops_per_s"]) * 100.0
+    peak = run.peak["bf16_flops_per_s"] * run.chips
+    return flops / ((hi - lo) * 1e-9 * peak) * 100.0

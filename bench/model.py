@@ -1,9 +1,11 @@
 """Model configurations and weights, built from ``bench/configs/<name>.json``.
 
 The configuration file holds the published sizes (the keys of the model's
-public ``config.json``) and the engine's knobs.  The program's
-``ModelConfig`` is built from it here, so an edit to the program's own
-config modules does not move the benchmark.
+public ``config.json``) and the engine's knobs.  Its ``model_type`` names the module of its
+architecture, ``bench/families/<model_type>.py`` (``family``), which
+builds the program's ``ModelConfig``, names the leaves and runs the
+reference's forward pass; so an edit to the program's own config modules
+does not move the benchmark, and a new architecture is one new file.
 
 Weights are random and drawn from ``--seed``.  Every leaf of every layer
 comes from its own key, ``fold_in(fold_in(seed, leaf), layer)``, so the
@@ -14,6 +16,7 @@ anything the program holds.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import zlib
 from pathlib import Path
@@ -30,34 +33,26 @@ def load_config(name: str, data: Path = BENCH) -> dict:
     return json.loads((data / "configs" / f"{name}.json").read_text())
 
 
+def family(spec: dict):
+    """The module of the configuration's architecture,
+    ``bench/families/<model_type>.py``."""
+    name = spec["model_type"]
+    path = BENCH / "families" / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"{spec['name']}: model_type {name!r} has no family "
+                         f"module {path.relative_to(BENCH.parent)}")
+    return importlib.import_module(f"families.{name}")
+
+
 def dims(spec: dict) -> dict:
     """The sizes the benchmark computes with, from the published keys."""
-    d = spec["hidden_size"]
-    H = spec["num_attention_heads"]
-    return {
-        "L": spec["num_hidden_layers"], "d": d, "H": H,
-        "Hkv": spec["num_key_value_heads"], "D": spec.get("head_dim", d // H),
-        "ff": spec["intermediate_size"], "V": spec["vocab_size"],
-        "eps": spec["rms_norm_eps"], "theta": spec["rope_theta"],
-        "tied": spec["tie_word_embeddings"],
-        "qkv_bias": spec["model_type"] == "qwen2",
-    }
+    return family(spec).dims(spec)
 
 
 def model_config(spec: dict):
     """The program's ``ModelConfig`` for this configuration."""
-    from repro.config import ModelConfig
-
-    m = dims(spec)
-    if spec["hidden_act"] != "silu":
-        raise ValueError(f"{spec['name']}: only the silu (SwiGLU) MLP is served")
-    return ModelConfig(
-        name=spec["name"], family="dense", citation=spec["source"],
-        n_layers=m["L"], d_model=m["d"], n_heads=m["H"], n_kv_heads=m["Hkv"],
-        d_ff=m["ff"], vocab_size=m["V"], head_dim=m["D"],
-        qkv_bias=m["qkv_bias"], rope_theta=float(m["theta"]),
-        tie_embeddings=m["tied"], norm_eps=float(m["eps"]),
-        param_dtype="bfloat16", activation_dtype="bfloat16")
+    fam = family(spec)
+    return fam.model_config(spec, fam.dims(spec))
 
 
 def seed_key_data(seed: int) -> np.ndarray:
@@ -66,36 +61,6 @@ def seed_key_data(seed: int) -> np.ndarray:
     if not 0 <= seed < 2 ** 64:
         raise ValueError(f"seed {seed} outside [0, 2**64)")
     return np.array([seed >> 32, seed & 0xFFFFFFFF], np.uint32)
-
-
-def _layer_leaves(m: dict) -> dict:
-    """Per-layer leaves: name -> (shape, kind).  Kinds: 'proj' (std
-    1/sqrt(fan_in)), 'scale' (1 + 0.1 N), 'bias' (0.02 N)."""
-    d, H, Hkv, D, ff = m["d"], m["H"], m["Hkv"], m["D"], m["ff"]
-    leaves = {
-        "ln1.scale": ((d,), "scale"),
-        "attn.w_q": ((d, H * D), "proj"),
-        "attn.w_k": ((d, Hkv * D), "proj"),
-        "attn.w_v": ((d, Hkv * D), "proj"),
-        "attn.w_o": ((H * D, d), "proj"),
-        "ln2.scale": ((d,), "scale"),
-        "mlp.w_gate": ((d, ff), "proj"),
-        "mlp.w_up": ((d, ff), "proj"),
-        "mlp.w_down": ((ff, d), "proj"),
-    }
-    if m["qkv_bias"]:
-        leaves.update({"attn.b_q": ((H * D,), "bias"),
-                       "attn.b_k": ((Hkv * D,), "bias"),
-                       "attn.b_v": ((Hkv * D,), "bias")})
-    return leaves
-
-
-def _top_leaves(m: dict) -> dict:
-    leaves = {"embed": ((m["V"], m["d"]), "embed"),
-              "final_norm.scale": ((m["d"],), "scale")}
-    if not m["tied"]:
-        leaves["lm_head"] = ((m["d"], m["V"]), "proj")
-    return leaves
 
 
 def _draw(key, name: str, shape, kind: str, layer):
@@ -126,11 +91,14 @@ def _key(key_data):
     return jax.random.wrap_key_data(key_data, impl="threefry2x32")
 
 
-def served_params(spec: dict, seed: int):
+def served_params(spec: dict, seed: int, mesh=None):
     """The program's parameter pytree, bfloat16, made on the device in one
-    jitted call."""
-    m = dims(spec)
-    layer_leaves, top_leaves = _layer_leaves(m), _top_leaves(m)
+    jitted call.  With a ``mesh``, each leaf is made where the engine
+    places it (``repro.launch.sharding.params_pspecs`` under the serving
+    rules), so no device holds more than its share."""
+    fam = family(spec)
+    m = fam.dims(spec)
+    layer_leaves, top_leaves = fam.layer_leaves(m), fam.top_leaves(m)
 
     def make(key_data):
         key = _key(key_data)
@@ -142,13 +110,19 @@ def served_params(spec: dict, seed: int):
         out["blocks"] = _nest(blocks)
         return out
 
-    return jax.jit(make)(jnp.asarray(seed_key_data(seed)))
+    key_data = jnp.asarray(seed_key_data(seed))
+    if mesh is None:
+        return jax.jit(make)(key_data)
+    from repro.launch.sharding import SERVING_LOGICAL_MAP, params_pspecs
+
+    shardings = params_pspecs(mesh, jax.eval_shape(make, key_data),
+                              SERVING_LOGICAL_MAP)
+    return jax.jit(make, out_shardings=shardings)(key_data)
 
 
-def _make_layer(spec_key: tuple):
-    m = dict(spec_key)
-    leaves = _layer_leaves(m)
-
+def _make_leaves(leaves: dict):
+    """A jitted draw of ``leaves`` in float32 from the key data and a layer
+    index."""
     @jax.jit
     def make(key_data, layer):
         key = _key(key_data)
@@ -157,31 +131,18 @@ def _make_layer(spec_key: tuple):
     return make
 
 
-def _make_top(spec_key: tuple):
-    m = dict(spec_key)
-    leaves = _top_leaves(m)
-
-    @jax.jit
-    def make(key_data):
-        key = _key(key_data)
-        return {n: _draw(key, n, s, k, 0).astype(jnp.float32)
-                for n, (s, k) in leaves.items()}
-    return make
-
-
 class ReferenceWeights:
     """Float32 copies of the served weights, one layer at a time."""
 
     def __init__(self, spec: dict, seed: int):
-        m = dims(spec)
-        key = tuple(sorted(m.items()))
-        self.dims = m
+        self.family = family(spec)
+        self.dims = m = self.family.dims(spec)
         self._key_data = jnp.asarray(seed_key_data(seed))
-        self._layer = _make_layer(key)
-        self._top = _make_top(key)
+        self._layer = _make_leaves(self.family.layer_leaves(m))
+        self._top = _make_leaves(self.family.top_leaves(m))
 
     def top(self) -> dict:
-        return self._top(self._key_data)
+        return self._top(self._key_data, jnp.uint32(0))
 
     def layer(self, i: int) -> dict:
         return self._layer(self._key_data, jnp.uint32(i))

@@ -1,16 +1,13 @@
 """The FLOP and byte counts against a hand count at one shape."""
 import pytest
 
-from counts import (chunk_flops, least_time, matmul_params,
-                    paged_attention_work, peaks, token_flops)
+from counts import (chunk_flops, least_time, paged_attention_work, peaks,
+                    token_flops)
 
-# L=2, d=8, H=4, Hkv=2, D=2, ff=16, V=10
-M = {"L": 2, "d": 8, "H": 4, "Hkv": 2, "D": 2, "ff": 16, "V": 10}
-
-
-def test_matmul_params():
-    # per layer: q 8*8 + k,v 2*8*4 + o 8*8 + mlp 3*8*16 = 64+64+64+384
-    assert matmul_params(M) == 2 * 576 + 80
+# L=2, d=8, H=4, Hkv=2, D=2, ff=16, V=10; the llama family's count of
+# multiplied weights (test_families.test_matmul_params)
+M = {"L": 2, "d": 8, "H": 4, "Hkv": 2, "D": 2, "ff": 16, "V": 10,
+     "matmul_params": 1232}
 
 
 def test_token_and_chunk_flops():
@@ -30,9 +27,12 @@ def test_paged_attention_work():
 
 def test_least_time_and_peaks():
     p = peaks("TPU v5 lite")
-    t, bound = least_time(197e12, 1.0, p)
+    t, bound = least_time(197e12, 1.0, p, 1)
     assert (t, bound) == (pytest.approx(1.0), "compute")
-    t, bound = least_time(1.0, 819e9, p)
+    t, bound = least_time(1.0, 819e9, p, 1)
     assert (t, bound) == (pytest.approx(1.0), "memory")
+    # four chips: a quarter of the time, bound by the same resource
+    assert least_time(197e12, 1.0, p, 4) == (pytest.approx(0.25), "compute")
+    assert least_time(1.0, 819e9, p, 4) == (pytest.approx(0.25), "memory")
     with pytest.raises(KeyError):
         peaks("TPU v9 imaginary")

@@ -20,7 +20,6 @@ SYNTH = devtrace.Trace(
                ("%fusion.2 = f32[] fusion()", 60, 70),
                ("%paged_decode_attention_kernel.9 = bf16[] custom-call()", 120, 180)]},
     modules={"0": [("jit_decode_step(1)", 10, 70), ("jit_decode_step(2)", 120, 180)]},
-    execs=[8, 118],
     spans=[("bench.tick", 0, 100), ("bench.tick", 100, 200)],
     python=[("engine.step", 5, 95), ("engine.read", 40, 60),
             ("engine.read", 70, 90), ("engine.step", 105, 195),
@@ -29,7 +28,8 @@ SYNTH = devtrace.Trace(
 
 
 def _run(trace=None, decode_calls=(), chunk_calls=(), config="smollm-360m"):
-    return cell.Run(cell={"config": config}, dims={}, peak=None, seconds=1,
+    return cell.Run(cell={"config": config}, dims={}, peak=None, chips=1,
+                    seconds=1,
                     setup_s=1.0, window=(0, 1), ticks=[], requests=[],
                     decode_calls=list(decode_calls),
                     chunk_calls=list(chunk_calls), trace=trace)
@@ -90,7 +90,7 @@ def test_launch_readers_match_engine_counters(monkeypatch):
     monkeypatch.setattr(model, "load_config",
                         lambda name, data=DATA: load(name, DATA))
     spec = load("tiny-gqa", DATA)
-    eng = cell.build_engine(spec, seed=3)
+    eng = cell.build_engine(spec, seed=3, chips=1)
     cell.warm_up(eng, spec)
     tracer = cell.Tracer(eng)
     rng = np.random.default_rng(0)
