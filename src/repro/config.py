@@ -88,7 +88,15 @@ class ModelConfig:
     mrope_sections: tuple = (16, 24, 24)   # (t, h, w) split of head_dim/2
     tie_embeddings: bool = False
     mlp_type: str = "swiglu"       # "swiglu" | "gelu" (GPT-BigCode/whisper)
+    gelu_approximate: bool = True  # gelu MLP: tanh form; False -> exact erf
     norm_eps: float = 1e-6
+    norm_kind: str = "rmsnorm"     # "rmsnorm" | "layernorm" (weight + bias)
+    # token positions: "rope" rotates q/k; "learned" adds row p of an
+    # (n_positions, d_model) table to the embedding (GPT-BigCode) and
+    # applies no rotation
+    position_kind: str = "rope"
+    n_positions: int = 0           # rows of the learned position table
+    attn_out_bias: bool = False    # bias b_o on the attention output
     # sliding-window attention (enables long_500k on quadratic archs)
     sliding_window: int = 0        # 0 -> full attention
     # family-specific sub-configs
@@ -122,6 +130,13 @@ class ModelConfig:
     def __post_init__(self):
         if self.family not in VALID_FAMILIES:
             raise ValueError(f"unknown family {self.family!r}")
+        if self.norm_kind not in ("rmsnorm", "layernorm"):
+            raise ValueError(f"unknown norm_kind {self.norm_kind!r}")
+        if self.position_kind not in ("rope", "learned"):
+            raise ValueError(f"unknown position_kind {self.position_kind!r}")
+        if self.position_kind == "learned" and self.n_positions < 1:
+            raise ValueError(f"{self.name}: learned positions need "
+                             "n_positions >= 1")
         if self.n_kv_heads and self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"{self.name}: n_heads={self.n_heads} not divisible by "

@@ -22,6 +22,7 @@ CONFIG = ModelConfig(
     head_dim=64,
     qkv_bias=True,
     is_encoder_decoder=True,
+    norm_kind="layernorm",
     n_encoder_layers=4,
     n_audio_frames=1500,
 )

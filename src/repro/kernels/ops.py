@@ -6,6 +6,7 @@ from __future__ import annotations
 import functools
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.conf_gate import confidence_gate_kernel
 from repro.kernels.decode_attention import decode_attention_kernel
@@ -14,6 +15,7 @@ from repro.kernels.paged_decode_attention import paged_decode_attention_kernel
 from repro.kernels.int8_quant import int8_quantize_kernel
 from repro.kernels.ssm_scan import ssm_chunk_scan_kernel
 from repro.kernels import ref  # noqa: F401  (re-exported for tests)
+from repro.models.pspec import current_mesh, pspec_for
 
 
 @functools.lru_cache(maxsize=1)
@@ -22,17 +24,10 @@ def on_tpu() -> bool:
 
 
 def paged_kernel_ok() -> bool:
-    """Whether the Pallas paged-decode kernel may serve this trace: TPU
-    only, and only OUTSIDE mesh rules.  Under a serving mesh the KV pool
-    is head-sharded and attention must flow through the jnp gather path,
-    which GSPMD partitions per shard — the kernel's block-table DMA
-    index_map addresses one un-sharded pool and would read a quarter
-    pool as if it were whole."""
-    if not on_tpu():
-        return False
-    from repro.models.pspec import current_mesh    # local: no jax device
-    # state is touched importing this module (same rule as on_tpu)
-    return current_mesh() is None
+    """Whether the Pallas paged-decode kernel may serve this trace: on a
+    TPU, with or without a serving mesh (under one it runs per device,
+    ``paged_decode_attention``)."""
+    return on_tpu()
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, **kw):
@@ -46,8 +41,27 @@ def decode_attention(q, k, v, kv_len, **kw):
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len, **kw):
-    return paged_decode_attention_kernel(q, k_pages, v_pages, block_tables,
-                                         kv_len, interpret=not on_tpu(), **kw)
+    """The paged kernel over the whole batch.  Under the serving mesh's
+    rules (``models.pspec``) it runs inside ``shard_map``: each device
+    calls it on its own query heads against its own shard of the pool,
+    which is ``Hkv / n`` KV heads where the ``model`` axis divides them
+    and the whole (replicated) pool where one KV head serves every query
+    head.  Otherwise (a KV group spread over devices) every device runs
+    the whole batch on replicated operands.  Block tables and lengths
+    are replicated."""
+    call = functools.partial(paged_decode_attention_kernel,
+                             interpret=not on_tpu(), **kw)
+    mesh = current_mesh()
+    if mesh is None:
+        return call(q, k_pages, v_pages, block_tables, kv_len)
+    kv_spec = pspec_for(k_pages.shape, [None, None, "model", None])
+    q_spec = pspec_for(q.shape, [None, "model", None])
+    if kv_spec[2] is None and k_pages.shape[2] != 1:
+        q_spec = P()                  # a device would hold part of a group
+    return jax.shard_map(call, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, P(), P()),
+                         out_specs=q_spec, check_vma=False)(
+        q, k_pages, v_pages, block_tables, kv_len)
 
 
 def ssm_chunk_scan(x, dt, A, Bm, Cm, *, chunk=256, **kw):

@@ -7,7 +7,9 @@ Strategy (DESIGN.md §4):
     weight, gathered per-layer inside the scan by GSPMD;
   * batch over ("pod", "data");
   * KV caches: kv-heads over "model" when divisible, else cache sequence
-    over "model" (MQA archs — flash-decoding-style partial softmax).
+    over "model" (MQA archs — flash-decoding-style partial softmax);
+    the serving engine's paged pool is replicated instead, with the k/v
+    projections that fill it (``params_pspecs(..., cfg=)``).
 
 All assignments are divisibility-aware (models.pspec): a rule that does
 not divide a concrete dim falls back to replication for that dim.
@@ -21,7 +23,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config import ModelConfig
-from repro.models.pspec import pspec_for, mesh_rules, set_mesh_rules
+from repro.models.pspec import pspec_for, mesh_rules, set_mesh_rules, shard_count
 
 # Sharding presets (hillclimbed in EXPERIMENTS.md §Perf):
 #   baseline  — TP over "model" + FSDP over "data", batch over (pod, data)
@@ -86,7 +88,7 @@ SERVING_LOGICAL_MAP = {
 # weights whose LAST dim is the contraction output fed back to d_model
 _DOWN_STYLE = ("w_o", "w_down", "out_proj")
 _REPLICATED = ("A_log", "D", "dt_bias", "b_if", "b_gates", "conv_w", "conv_b",
-               "scale", "bias", "b_q", "b_k", "b_v", "b_up", "b_down",
+               "scale", "bias", "b_q", "b_k", "b_v", "b_o", "b_up", "b_down",
                "router", "skip", "r_gates")
 
 
@@ -100,18 +102,25 @@ def _path_names(path) -> list:
     return out
 
 
-def param_logical_axes(path, leaf) -> list:
-    """Return the logical axis names for one parameter leaf."""
+def param_logical_axes(path, leaf, cfg: Optional[ModelConfig] = None) -> list:
+    """Return the logical axis names for one parameter leaf.  Given the
+    ``cfg`` (and installed mesh rules), the k/v projections of a model
+    whose KV heads the "model" axis cannot split are replicated: every
+    device then computes the KV heads whole, as its replica of the paged
+    pool needs, instead of a column slice that cuts through a head."""
     names = _path_names(path)
     last = names[-1] if names else ""
     nd = leaf.ndim
     if last in _REPLICATED or nd <= 1:
         return [None] * nd
+    if (cfg is not None and cfg.mla is None and last in ("w_k", "w_v")
+            and shard_count("model", cfg.n_kv_heads) == 1):
+        return [None] * nd
     if last == "embed":
         return [None] * (nd - 2) + ["model", "fsdp"]      # (vocab, d)
     if last in ("lm_head",):
         return [None] * (nd - 2) + ["fsdp", "model"]      # (d, vocab)
-    if last == "dec_pos":
+    if last in ("dec_pos", "pos_embed"):
         return [None] * nd
     in_moe = "moe" in names and last in ("w_gate", "w_up", "w_down")
     if in_moe:
@@ -127,11 +136,13 @@ def param_logical_axes(path, leaf) -> list:
     return [None] * (nd - 2) + ["fsdp", "model"]
 
 
-def params_pspecs(mesh: Mesh, params_shape, logical_map=None) -> object:
-    """NamedSharding tree for a params pytree of ShapeDtypeStructs."""
+def params_pspecs(mesh: Mesh, params_shape, logical_map=None,
+                  cfg: Optional[ModelConfig] = None) -> object:
+    """NamedSharding tree for a params pytree of ShapeDtypeStructs (see
+    ``param_logical_axes`` for what ``cfg`` changes)."""
     with mesh_rules(mesh, logical_map):
         def one(path, leaf):
-            spec = pspec_for(leaf.shape, param_logical_axes(path, leaf))
+            spec = pspec_for(leaf.shape, param_logical_axes(path, leaf, cfg))
             return NamedSharding(mesh, spec if spec is not None else P())
         return jax.tree_util.tree_map_with_path(one, params_shape)
 

@@ -5,8 +5,8 @@ Three execution paths:
     ``lax.scan`` over query blocks with fp32 softmax, bounding peak
     activation memory to (block_q x seq) instead of (seq x seq).  It
     serves every platform: prefill chunks, speculative verify, and the
-    decode paths the Pallas paged kernel does not take (sliding window,
-    sharded pools).  No model path calls a Pallas flash kernel.
+    decode paths the Pallas paged kernel does not take (CPU, sliding
+    window).  No model path calls a Pallas flash kernel.
   * ``triangular`` — causal block-skipping variant (perf pass): query
     blocks are unrolled and each attends only keys ``<= block_end``,
     halving attention FLOPs vs the chunked path.
@@ -48,6 +48,8 @@ def init_attention(key, cfg: ModelConfig, d_in: Optional[int] = None) -> dict:
         p["b_q"] = jnp.zeros((cfg.n_heads * hd,), dt)
         p["b_k"] = jnp.zeros((cfg.n_kv_heads * hd,), dt)
         p["b_v"] = jnp.zeros((cfg.n_kv_heads * hd,), dt)
+    if cfg.attn_out_bias:
+        p["b_o"] = jnp.zeros((cfg.d_model,), dt)
     if cfg.qk_norm:
         p["q_norm"] = L.init_rmsnorm(hd, dt)
         p["k_norm"] = L.init_rmsnorm(hd, dt)
@@ -98,6 +100,13 @@ def _project_qkv(p: dict, cfg: ModelConfig, x, xkv=None):
     k = shard(k, "batch", None, "model", None)
     v = shard(v, "batch", None, "model", None)
     return q, k, v
+
+
+def out_proj(p: dict, o):
+    """The attention output projection, with its bias where the config
+    has one (``attn_out_bias``)."""
+    out = o @ p["w_o"]
+    return out + p["b_o"] if "b_o" in p else out
 
 
 # --------------------------------------------------------------------------
@@ -240,7 +249,7 @@ def attention_fwd(p: dict, cfg: ModelConfig, x, positions, *,
     else:
         o = chunked_attention(q, k, v, causal=causal, window=window)
     o = shard(o, "batch", None, "model", None)
-    out = o.reshape(B, S, -1) @ p["w_o"]
+    out = out_proj(p, o.reshape(B, S, -1))
     if return_kv:
         return out, (k, v)
     return out
@@ -292,7 +301,7 @@ def attention_decode(p: dict, cfg: ModelConfig, x, cache_k, cache_v,
     # so masking by validity is sufficient (rope already encoded order).
     o = chunked_attention(q, cache_k, cache_v, causal=False,
                           kv_len=kv_len)
-    out = o.reshape(B, 1, -1) @ p["w_o"]
+    out = out_proj(p, o.reshape(B, 1, -1))
     return out, cache_k, cache_v
 
 
@@ -335,9 +344,10 @@ def paged_prefill_attention(p: dict, cfg: ModelConfig, x, pool_k, pool_v,
     q, k, v = _project_qkv(p, cfg, x)
     pos, page, off = _chunk_page_targets(pos_offset, C, n_valid, ps,
                                          block_tables)
-    posv = jnp.broadcast_to(pos[None], (B, C))
-    q = L.apply_rope(q, posv, cfg.rope_theta)
-    k = L.apply_rope(k, posv, cfg.rope_theta)
+    if cfg.position_kind == "rope":
+        posv = jnp.broadcast_to(pos[None], (B, C))
+        q = L.apply_rope(q, posv, cfg.rope_theta)
+        k = L.apply_rope(k, posv, cfg.rope_theta)
     pool_k = pool_k.at[page, off].set(k[0].astype(pool_k.dtype))
     pool_v = pool_v.at[page, off].set(v[0].astype(pool_v.dtype))
     kg = pool_k[block_tables.reshape(-1)].reshape(1, -1, *pool_k.shape[2:])
@@ -346,7 +356,7 @@ def paged_prefill_attention(p: dict, cfg: ModelConfig, x, pool_k, pool_v,
     vg = shard(vg, "batch", None, "model", None)
     o = chunked_attention(q, kg, vg, causal=True, q_offset=pos_offset,
                           window=window, kv_len=pos_offset + n_valid)
-    out = o.reshape(B, C, -1) @ p["w_o"]
+    out = out_proj(p, o.reshape(B, C, -1))
     return out, pool_k, pool_v
 
 
@@ -390,7 +400,8 @@ def paged_attention_decode(p: dict, cfg: ModelConfig, x, pool_k, pool_v,
     # importable without touching the Pallas toolchain at module load
     if window == 0 and ops.paged_kernel_ok():
         # the Pallas kernel streams pages by block-table lookup in the
-        # DMA index_map — no contiguous gather is ever materialized
+        # DMA index_map — no contiguous gather is ever materialized;
+        # under a serving mesh each device runs it on its own heads
         o = ops.paged_decode_attention(q[:, 0], pool_k, pool_v,
                                        block_tables, pos + 1)[:, None]
     else:
@@ -405,7 +416,7 @@ def paged_attention_decode(p: dict, cfg: ModelConfig, x, pool_k, pool_v,
         kv_start = jnp.maximum(pos + 1 - window, 0) if window else None
         o = chunked_attention(q, kg, vg, causal=False, kv_len=pos + 1,
                               kv_start=kv_start)
-    out = o.reshape(B, 1, -1) @ p["w_o"]
+    out = out_proj(p, o.reshape(B, 1, -1))
     return out, pool_k, pool_v
 
 

@@ -104,9 +104,12 @@ def init_gelu_mlp(key, d_model: int, d_ff: int, dtype) -> dict:
     }
 
 
-def gelu_mlp(params: dict, x: jax.Array) -> jax.Array:
+def gelu_mlp(params: dict, x: jax.Array,
+             approximate: bool = True) -> jax.Array:
+    """``approximate``: the tanh form of GELU; False, the exact erf form."""
     h = x @ params["w_up"] + params["b_up"]
-    h = jax.nn.gelu(h.astype(jnp.float32)).astype(x.dtype)
+    h = jax.nn.gelu(h.astype(jnp.float32),
+                    approximate=approximate).astype(x.dtype)
     h = shard(h, "batch", None, "model") if h.ndim == 3 else h
     return h @ params["w_down"] + params["b_down"]
 

@@ -40,7 +40,7 @@ def _init_attn_block(key, cfg: ModelConfig, use_moe: bool,
                      cross: bool = False, d_in: Optional[int] = None) -> dict:
     dt = L.dtype_of(cfg.param_dtype)
     ks = jax.random.split(key, 4)
-    ln = cfg.is_encoder_decoder            # whisper uses LayerNorm w/ bias
+    ln = _layernorm(cfg)
     p = {"ln1": L.init_norm(d_in or cfg.d_model, dt, ln)}
     if cfg.mla is not None:
         p["attn"] = A.init_mla(ks[0], cfg)
@@ -66,6 +66,7 @@ def _attn_block_fwd(p, cfg, x, positions, *, causal=True, window=0,
                     return_kv=False, x_extra=None):
     """Pre-norm residual block.  Returns (x, aux, kv or None)."""
     eps = cfg.norm_eps
+    rope = rope and cfg.position_kind == "rope"
     if x_extra is not None:                    # zamba2 shared block: concat
         h_in = L.norm(p["ln1"], jnp.concatenate([x, x_extra], axis=-1), eps)
     else:
@@ -96,7 +97,7 @@ def _attn_block_fwd(p, cfg, x, positions, *, causal=True, window=0,
         y, aux = M.moe_fwd(p["moe"], cfg, h, dispatch=moe_dispatch,
                            drop_free=moe_drop_free, capacity=moe_capacity)
     elif "b_up" in p.get("mlp", {}):
-        y = L.gelu_mlp(p["mlp"], h)
+        y = L.gelu_mlp(p["mlp"], h, cfg.gelu_approximate)
     else:
         y = L.swiglu(p["mlp"], h)
     return x + y, aux, kv
@@ -108,6 +109,7 @@ def _attn_block_decode(p, cfg, x, cache, pos, *, window=0, x_extra=None,
     leaves — per-slot rows when ``block_tables`` is None, else the
     layer's slice of the paged pool, indexed through the tables."""
     eps = cfg.norm_eps
+    rope = rope and cfg.position_kind == "rope"
     if x_extra is not None:
         h_in = L.norm(p["ln1"], jnp.concatenate([x, x_extra], axis=-1), eps)
     else:
@@ -145,7 +147,7 @@ def _attn_block_decode(p, cfg, x, cache, pos, *, window=0, x_extra=None,
         y, _ = M.moe_fwd(p["moe"], cfg, h, dispatch="einsum",
                          drop_free=True)
     elif "b_up" in p.get("mlp", {}):
-        y = L.gelu_mlp(p["mlp"], h)
+        y = L.gelu_mlp(p["mlp"], h, cfg.gelu_approximate)
     else:
         y = L.swiglu(p["mlp"], h)
     return x + y, new_cache
@@ -160,7 +162,7 @@ def _cross_decode(p, cfg, q_in, xk, xv):
         q = q + p["b_q"]
     q = q.reshape(B, 1, cfg.n_heads, hd)
     o = A.chunked_attention(q, xk, xv, causal=False)
-    return o.reshape(B, 1, -1) @ p["w_o"]
+    return A.out_proj(p, o.reshape(B, 1, -1))
 
 
 # ==========================================================================
@@ -197,6 +199,20 @@ def _stacked_init(key, n: int, init_one):
     return jax.vmap(init_one)(jax.random.split(key, n))
 
 
+def _layernorm(cfg: ModelConfig) -> bool:
+    """LayerNorm with weight and bias (whisper, GPT-BigCode), else RMSNorm."""
+    return cfg.norm_kind == "layernorm"
+
+
+def _add_positions(params, x, positions):
+    """Learned absolute positions: add row ``positions[b, s]`` of the
+    ``(n_positions, d_model)`` table to ``x[b, s]``.  A position past the
+    table reads its last row (only chunk pads reach one)."""
+    tab = params["pos_embed"]
+    rows = jnp.take(tab, jnp.minimum(positions, tab.shape[0] - 1), axis=0)
+    return x + rows.astype(x.dtype)
+
+
 # ==========================================================================
 # init
 # ==========================================================================
@@ -206,8 +222,11 @@ def init_params(key, cfg: ModelConfig, max_seq: int = 4096) -> dict:
     keys = jax.random.split(key, 16)
     p: dict = {
         "embed": L.init_embedding(keys[0], cfg.vocab_size, cfg.d_model, dt),
-        "final_norm": L.init_norm(cfg.d_model, dt, cfg.is_encoder_decoder),
+        "final_norm": L.init_norm(cfg.d_model, dt, _layernorm(cfg)),
     }
+    if cfg.position_kind == "learned":
+        p["pos_embed"] = L.embed_init(keys[6], (cfg.n_positions, cfg.d_model),
+                                      dt)
     if not cfg.tie_embeddings:
         p["lm_head"] = L.dense_init(keys[1], (cfg.d_model, cfg.vocab_size), dt)
 
@@ -334,6 +353,8 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
         positions = mrope_positions(cfg, B, pe.shape[1], S_text)
     else:
         positions = jnp.broadcast_to(jnp.arange(x.shape[1])[None], x.shape[:2])
+        if cfg.position_kind == "learned":
+            x = _add_positions(params, x, positions)
     x = shard(x, "batch", None, None)
     aux_total = jnp.zeros((), F32)
     cache = {}
@@ -842,6 +863,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
             f"paged decode unsupported for family {cfg.family!r} "
             "(recurrent families keep their fixed-size state path)")
     x = L.embed(params["embed"], tokens)
+    if cfg.position_kind == "learned":
+        x = _add_positions(params, x, jnp.broadcast_to(
+            jnp.reshape(jnp.asarray(pos, jnp.int32), (-1, 1)), tokens.shape))
     rope = fam != "audio"
     rope_pos = None
     if fam == "audio":
@@ -945,10 +969,9 @@ def _attn_block_prefill_chunk(p, cfg, x, cache, pos_offset, n_valid,
                                             pos_offset, n_valid, block_tables)
         new_cache = {"ckv": ckv, "krope": krope}
     else:
-        a, k, v = A.paged_prefill_attention(p["attn"], cfg, h_in,
-                                            cache["k"], cache["v"],
-                                            pos_offset, n_valid, block_tables,
-                                            window=window)
+        a, k, v = A.paged_prefill_attention(
+            p["attn"], cfg, h_in, cache["k"], cache["v"], pos_offset, n_valid,
+            block_tables, window=window)
         new_cache = {"k": k, "v": v}
     x = x + a
     aux = jnp.zeros((), F32)
@@ -959,7 +982,7 @@ def _attn_block_prefill_chunk(p, cfg, x, cache, pos_offset, n_valid,
         y, aux = M.moe_fwd(p["moe"], cfg, h, dispatch="einsum",
                            drop_free=True, capacity=moe_capacity)
     elif "b_up" in p.get("mlp", {}):
-        y = L.gelu_mlp(p["mlp"], h)
+        y = L.gelu_mlp(p["mlp"], h, cfg.gelu_approximate)
     else:
         y = L.swiglu(p["mlp"], h)
     return x + y, new_cache, aux
@@ -999,6 +1022,9 @@ def prefill_chunk(params: dict, cfg: ModelConfig, cache: dict, tokens,
             "(recurrent families keep their monolithic prefill path)")
     window = cfg.sliding_window
     x = L.embed(params["embed"], tokens)
+    if cfg.position_kind == "learned":
+        x = _add_positions(params, x,
+                           pos_offset + jnp.arange(tokens.shape[1])[None])
     aux_total = jnp.zeros((), F32)
     new_cache: dict = {}
 

@@ -456,18 +456,24 @@ class PagedSlotManager(_SlotOccupancy):
         self.prefill_positions_skipped = 0   # prompt positions attached by
         #                                      reference (never recomputed)
         self.max_bt = pages_for(max_seq, page_size)
-        self.cache = T.init_paged_cache(cfg, pool_pages + 1, page_size)
         self.states: List[Optional[_PagedSlotState]] = [None] * n_slots
         if mesh is None:
+            self.cache = T.init_paged_cache(cfg, pool_pages + 1, page_size)
             self._graft = jax.jit(T.graft_paged_cache)
             self._copy = jax.jit(T.copy_paged_pages)
         else:
             # place the pool: KV heads (MLA latent rank) over "model",
             # the layer/page/offset axes whole on every device — so the
             # extract gather below still device_gets a token-exact global
-            # snapshot and graft scatters host pages back under GSPMD
-            pool_sh = paged_cache_pspecs(mesh, cfg, self.cache, logical_map)
-            self.cache = jax.device_put(self.cache, pool_sh)
+            # snapshot and graft scatters host pages back under GSPMD.
+            # Each device makes its own share in place: a pool made whole
+            # on the first device and then placed would sit there at once
+            # in full, beyond that device's share
+            def make():
+                return T.init_paged_cache(cfg, pool_pages + 1, page_size)
+            pool_sh = paged_cache_pspecs(mesh, cfg, jax.eval_shape(make),
+                                         logical_map)
+            self.cache = jax.jit(make, out_shardings=pool_sh)()
             # pin the output sharding of every pool-rewriting callable:
             # scatter sharding inference CAN keep the operand layout, but
             # pinning it makes resharding impossible rather than unlikely
@@ -865,8 +871,9 @@ class ContinuousEngine:
         if mesh is not None:
             # tensor-parallel placement: attention/FFN weights split over
             # "model", experts expert-parallel, everything else replicated
+            # (k/v projections too where the KV heads do not split)
             params = jax.device_put(
-                params, params_pspecs(mesh, params, self.logical_map))
+                params, params_pspecs(mesh, params, self.logical_map, cfg))
         self.params = params
         self.max_seq = max_seq
         self.kv_layout = kv_layout

@@ -99,6 +99,12 @@ def _engine_tables(lens, ps, max_bt, rng):
                 id="smollm-360m-served-width"),
     _paged_case(2, 20, 20, 128, 16, 128, (1500, 1),
                 id="qwen1.5-4b-served-width"),
+    # granite-20b: 48 query heads over one KV head, on one chip (g 48)
+    # and on each chip of four (g 12); ragged lengths across blocks
+    _paged_case(3, 48, 1, 128, 16, 128, (2048, 777, 1),
+                id="granite-20b-one-chip"),
+    _paged_case(4, 12, 1, 128, 16, 128, (1, 1100, 129, 16),
+                id="granite-20b-per-chip-of-four"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_kernel(B, H, Hkv, D, ps, max_bt, lens, dtype):

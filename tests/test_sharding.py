@@ -117,6 +117,39 @@ def test_cache_axes_mqa_seq_sharding():
     assert la2 == [None, "batch", None, "model", None]
 
 
+def test_mqa_serving_placement_over_four():
+    """granite-20b (one KV head) on the 1 x 4 serving mesh: the k/v
+    projections and their biases are whole on every device, as is the
+    paged pool they fill (no per-layer gather of a column-split head);
+    the query heads split 12 to a device, and w_q, w_o, w_up, w_down and
+    the vocabulary of the (tied) embedding are quartered."""
+    cfg = get_config("granite-20b")
+    shapes = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    pool = jax.eval_shape(lambda: T.init_paged_cache(cfg, 17, 16))
+    mesh = _abstract_mesh((1, 4), ("data", "model"))
+    with PS.mesh_rules(mesh, SH.SERVING_LOGICAL_MAP):
+        specs = {"/".join(SH._path_names(path)): PS.pspec_for(
+            leaf.shape, SH.param_logical_axes(path, leaf, cfg))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(shapes)}
+        old_w_k = PS.pspec_for(shapes["blocks"]["attn"]["w_k"].shape,
+                               [None, "fsdp", "model"])
+        pool_specs = [PS.pspec_for(leaf.shape, SH.paged_cache_logical_axes(
+            cfg, path, leaf))
+            for path, leaf in jax.tree_util.tree_leaves_with_path(pool)]
+    quartered = {"blocks/attn/w_q": P(None, None, "model"),
+                 "blocks/attn/w_o": P(None, "model", None),
+                 "blocks/mlp/w_up": P(None, None, "model"),
+                 "blocks/mlp/w_down": P(None, "model", None),
+                 "embed": P("model", None)}
+    for name, spec in specs.items():
+        assert spec == quartered.get(name, P(*[None] * len(spec))), name
+    assert shapes["blocks"]["attn"]["w_q"].shape[-1] // 4 == 12 * 128
+    # without the config the column rule would cut the one head in four
+    assert old_w_k == P(None, None, "model")
+    assert specs["blocks/attn/w_k"] == P(None, None, None)
+    assert pool_specs == [P(None, None, None, None, None)] * 2
+
+
 # ---------------------------------------------------------------------------
 # mesh-sharded serving engine
 # ---------------------------------------------------------------------------

@@ -16,10 +16,13 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.config import get_config
 from repro.configs import tiansuan_pair as TP
+from repro.launch.sharding import (SERVING_LOGICAL_MAP, paged_cache_pspecs,
+                                   params_pspecs)
+from repro.models.pspec import mesh_rules
 from repro.kernels import ops
 from repro.kernels.conf_gate import confidence_gate_kernel
 from repro.kernels.paged_decode_attention import paged_decode_attention_kernel
@@ -34,6 +37,10 @@ PAGED_WIDTHS = {
     "tiansuan-ground": (8, 8, 4, 48),
     "tiansuan-onboard": (8, 4, 2, 48),
     "qwen1.5-4b": (8, 20, 20, 128),
+    # 48 query heads over one KV head: all on one chip, or 12 on each of
+    # four under the serving mesh
+    "granite-20b": (8, 48, 1, 128),
+    "granite-20b-per-chip": (8, 12, 1, 128),
 }
 
 
@@ -118,3 +125,53 @@ def test_paged_decode_step_compiles_with_kernel(one_chip, monkeypatch, cfg):
         _spec((n_slots, pages_for(max_seq, ps)), jnp.int32, one_chip),
     ).compile()
     assert PALLAS_OP in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo, one_chip):
+    """The serving mesh (1 x 4, every chip on "model") over the described
+    host; ``one_chip`` holds the compilation cache off."""
+    return Mesh([topo.devices[:4]], ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def test_granite_decode_step_compiles_per_chip_on_four(four_chips,
+                                                       monkeypatch):
+    """granite-20b's decode step at published widths on the 1 x 4 serving
+    mesh, with the kernel gate as it reads on a TPU host: every chip runs
+    the Pallas kernel on its 12 query heads against its replica of the
+    pool (no gather path), the layers' all-reduces are in, and one step's
+    arguments, outputs and temporaries fit a chip's 16 GiB."""
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = get_config("granite-20b")
+    n_slots, ps, n_pages, max_bt = 48, 16, 3841, 128
+    mesh = four_chips
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, s: _spec(a.shape, a.dtype, s),
+                            tree, shardings)
+
+    pshape = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    cshape = jax.eval_shape(lambda: T.init_paged_cache(cfg, n_pages, ps))
+    params = placed(pshape, params_pspecs(mesh, pshape, SERVING_LOGICAL_MAP,
+                                          cfg))
+    cache = placed(cshape, paged_cache_pspecs(mesh, cfg, cshape,
+                                              SERVING_LOGICAL_MAP))
+    rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+    def step(p, c, t, pos, bt):
+        with mesh_rules(mesh, SERVING_LOGICAL_MAP):
+            return T.decode_step(p, cfg, c, t, pos, block_tables=bt)
+
+    compiled = jax.jit(step).lower(
+        params, cache, _spec((n_slots, 1), jnp.int32, rep),
+        _spec((n_slots,), jnp.int32, rep),
+        _spec((n_slots, max_bt), jnp.int32, rep)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%paged_decode_attention_kernel\.\d+ = bf16\[48,1,12,128\]",
+                     text)
+    assert "all-reduce" in text
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+    assert used < 16 * 2 ** 30, used
